@@ -113,6 +113,7 @@ def run_hier(full: bool = False, smoke: bool = False):
 
     from repro.dist.context import mesh_context
     from repro.dist.sharding import hier_momentum_sharding
+    from repro.launch.mesh import auto_mesh
     # NOT from repro.launch.dryrun — importing it would force the 512-device
     # placeholder platform via XLA_FLAGS before jax initializes
     from repro.utils import collective_bytes
@@ -123,7 +124,7 @@ def run_hier(full: bool = False, smoke: bool = False):
               "(XLA_FLAGS=--xla_force_host_platform_device_count=8)",
               file=sys.stderr)
         return []
-    mesh = jax.make_mesh((2, n_dev // 2), ("pod", "data"))
+    mesh = auto_mesh((2, n_dev // 2), ("pod", "data"))
     rows = []
     key = jax.random.PRNGKey(1)
     iters, warmup = (2, 1) if smoke else (5, 2)

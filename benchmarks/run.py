@@ -142,6 +142,8 @@ def main() -> None:
                     help="fast CI subset: reduced aggcost + agghier grids")
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     names = [n.strip() for n in args.only.split(",") if n.strip()] or list(BENCHES)
     unknown = [n for n in names if n not in BENCHES]
     if unknown:

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import aggregators as _flatagg
+from repro.kernels.backend import interpret_mode, on_tpu
 
 from .baselines import stacked_zeno, weighted_zeno
 from .spec import AggregatorSpec, SpecLike, parse
@@ -126,9 +127,7 @@ def resolve(spec: SpecLike, **kw) -> Callable:
         if sp.base not in _RULES:
             raise KeyError(f"unknown base rule {sp.base!r} in {sp.canonical!r}")
 
-    backend = sp.backend
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = _backend(sp)
     if backend == "pallas" and rule.pallas is not None:
         flat_fn = rule.pallas(sp)
     else:
@@ -163,7 +162,7 @@ def resolve(spec: SpecLike, **kw) -> Callable:
             if hfn is None and sp.backend == "auto" and rule.hier is not None:
                 hfn = rule.hier(sp)
             if hfn is not None:
-                fn = hfn
+                fn = _mesh_aware(hfn, fn)
             cache["fn"] = fn
         return cache["fn"]
 
@@ -192,6 +191,20 @@ def _is_flat_matrix(x) -> bool:
     return hasattr(x, "ndim") and x.ndim == 2
 
 
+def _mesh_aware(hier_fn: Callable, stacked_fn: Callable) -> Callable:
+    """The cross-pod variant when traced inside a multi-pod ``mesh_context``,
+    else the rule's own stacked path — the one its backend chose, kernels
+    included (decided at trace time, like ``dist.hierarchy``'s dispatch)."""
+    from repro.dist.context import current_axis_size
+
+    def agg(tree, s=None):
+        if current_axis_size(_hr().POD_AXIS) <= 1:
+            return stacked_fn(tree, s)
+        return hier_fn(tree, s)
+
+    return agg
+
+
 def _flatten_fallback(flat_fn: Callable) -> Callable:
     """Stacked adapter for rules with no native leaf-wise path: concatenate
     the (m, ...) leaves into one (m, d) matrix, run the flat rule, unflatten.
@@ -216,11 +229,16 @@ def _flatten_fallback(flat_fn: Callable) -> Callable:
 # Built-in rules
 # ---------------------------------------------------------------------------
 
+def _backend(sp: AggregatorSpec) -> str:
+    """The spec's backend with ``auto`` decided: pallas on TPU, else jnp."""
+    if sp.backend == "auto":
+        return "pallas" if on_tpu() else "jnp"
+    return sp.backend
+
+
 def _interp(sp: AggregatorSpec) -> bool:
     """Pallas interpret mode: explicit override, else Mosaic only on TPU."""
-    if sp.interpret is not None:
-        return sp.interpret
-    return jax.default_backend() != "tpu"
+    return interpret_mode(sp.interpret)
 
 
 def _split_kwargs(kw: dict, fn: Callable) -> tuple[dict, dict]:
@@ -256,6 +274,17 @@ def _stacked_base(sp: AggregatorSpec, default: str,
 
 def _cwtm_lam(sp: AggregatorSpec) -> float:
     return max(sp.lam, 1e-3)  # λ=0 would retain everything: degenerate band
+
+
+def _stacked_cwmed(sp: AggregatorSpec) -> Callable:
+    """Leaf-wise ω-CWMed; on the pallas backend each leaf runs the median
+    kernel, which reads the leaf in its own dtype and keeps its selection in
+    VMEM (the jnp oracle's sort materializes several f32 copies of every
+    leaf — more than a chip holds for a 10^8-element embedding)."""
+    if _backend(sp) == "pallas":
+        return partial(_stk().stacked_cwmed,
+                       median=partial(_ops().wcwmed, interpret=_interp(sp)))
+    return _stk().stacked_cwmed
 
 
 def _pallas_ctma(sp: AggregatorSpec) -> Callable:
@@ -329,7 +358,7 @@ def _register_builtins() -> None:
         "cwmed",
         flat=lambda sp: _flatagg.weighted_cwmed,
         pallas=lambda sp: partial(_ops().wcwmed, interpret=_interp(sp)),
-        stacked=lambda sp: _stk().stacked_cwmed,
+        stacked=_stacked_cwmed,
         hier=lambda sp: _hr().hier_cwmed,
         doc="ω-CWMed — weighted coordinate-wise median (Lemma C.3)",
     )

@@ -26,11 +26,13 @@ import jax.numpy as jnp
 Array = jnp.ndarray
 
 
-def _tie_tol(cw: Array, half: Array) -> Array:
+def tie_tol(cw: Array, half: Array) -> Array:
     """Tolerance for the exact-tie rule: a float32 cumsum of m weights carries
     up to ~m·eps relative rounding, so an exact (atol=0) comparison misses
     genuine ties once prefix sums round (e.g. integer-valued weights past
-    2^24). Scale the tolerance with the prefix length and the half-mass."""
+    2^24). Scale the tolerance with the prefix length and the half-mass.
+    ``cw`` is (m, ...) cumulative weights; the ω-CWMed kernel
+    (kernels/wcwmed.py) applies the same rule to its rank-order sums."""
     m = cw.shape[0]
     return 4.0 * m * jnp.finfo(cw.dtype).eps * jnp.abs(half)
 
@@ -77,8 +79,8 @@ def weighted_median_1d(v: Array, s: Array) -> Array:
     jstar = jnp.argmax(cw > half)  # first index strictly past half
     med = vs[jstar]
     # tie handling (mostly relevant for integer weights); the tolerance is
-    # relative — see _tie_tol — because the f32 cumsum rounds
-    tol = _tie_tol(cw, half)
+    # relative — see tie_tol — because the f32 cumsum rounds
+    tol = tie_tol(cw, half)
     tie = jnp.any(jnp.abs(cw[:-1] - half) <= tol)
     jtie = jnp.argmax(jnp.abs(cw - half) <= tol)
     tied = 0.5 * (vs[jtie] + vs[jnp.minimum(jtie + 1, v.shape[0] - 1)])
@@ -97,7 +99,7 @@ def weighted_cwmed(x: Array, s: Optional[Array] = None) -> Array:
     past = cw > half
     jstar = jnp.argmax(past, axis=0)                    # (d,)
     med = jnp.take_along_axis(xs, jstar[None], axis=0)[0]
-    tol = _tie_tol(cw, half)                            # (d,) relative tol
+    tol = tie_tol(cw, half)                            # (d,) relative tol
     tie = jnp.any(jnp.abs(cw[:-1] - half) <= tol, axis=0)
     jtie = jnp.argmax(jnp.abs(cw - half) <= tol, axis=0)
     vj = jnp.take_along_axis(xs, jtie[None], axis=0)[0]

@@ -53,11 +53,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # this container's 0.4.37 ships it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core.aggregators import weighted_cwmed, weighted_cwtm
 from repro.dist.context import current_axis_size, current_mesh
 from repro.dist import robust as _stk
@@ -210,9 +205,9 @@ def _run_hier(body: Callable, tree: Pytree, s: Optional[Array], mesh) -> Pytree:
     m = jax.tree_util.tree_leaves(tree)[0].shape[0]
     w = jnp.ones((m,), jnp.float32) if s is None else s.astype(jnp.float32)
     in_specs, out_specs, fracs, axes = _hier_specs(tree, mesh)
-    fn = _shard_map(lambda t, sw: body(t, sw, fracs, axes), mesh=mesh,
-                    in_specs=(in_specs, P()), out_specs=out_specs,
-                    check_rep=False)
+    fn = jax.shard_map(lambda t, sw: body(t, sw, fracs, axes), mesh=mesh,
+                       in_specs=(in_specs, P()), out_specs=out_specs,
+                       check_vma=False)
     return fn(tree, w)
 
 

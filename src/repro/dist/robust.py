@@ -88,12 +88,20 @@ def stacked_mean(tree: Pytree, s: Optional[Array] = None) -> Pytree:
     return _combine(tree, s, jnp.sum(s))
 
 
-def stacked_cwmed(tree: Pytree, s: Optional[Array] = None) -> Pytree:
-    """ω-CWMed is coordinate-wise, hence exactly leaf-separable."""
+def _jnp_median(x: Array, s: Array) -> Array:
+    return weighted_cwmed(x.astype(jnp.float32), s)
+
+
+def stacked_cwmed(tree: Pytree, s: Optional[Array] = None, *,
+                  median: Callable[[Array, Array], Array] = _jnp_median
+                  ) -> Pytree:
+    """ω-CWMed is coordinate-wise, hence exactly leaf-separable: ``median``
+    (the flat (m, d) rule — the jnp oracle, or the Pallas kernel that the
+    registry hands in on TPU) runs on each leaf's (m, d) view."""
     s = _weights(s, _lead(tree))
 
     def leaf(x):
-        return weighted_cwmed(_flat2(x).astype(jnp.float32), s).reshape(x.shape[1:])
+        return median(_flat2(x), s).reshape(x.shape[1:])
 
     return _tmap(leaf, tree)
 

@@ -1,9 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret=True`` (the default in this CPU container) runs the kernel bodies
-in the Pallas interpreter for correctness validation; on a real TPU deployment
-pass ``interpret=False`` to emit Mosaic kernels. ``use_pallas=False`` falls
-back to the pure-jnp oracle — the path the multi-pod dry-run lowers.
+``interpret=None`` (every entry point's default) lets the backend decide
+(kernels/backend.py): Mosaic kernels when the default backend is a TPU, the
+Pallas interpreter anywhere else. An explicit ``True``/``False`` wins.
+``use_pallas=False`` falls back to the pure-jnp oracle — the path the
+multi-pod dry-run lowers.
 
 HBM-pass accounting for the (m, d) update matrix X (see wctma_fused.py):
 
@@ -36,7 +37,7 @@ from .swa import (paged_decode_pallas, ragged_paged_decode_pallas,
 
 @partial(jax.jit, static_argnames=("interpret",))
 def wmean(x: jnp.ndarray, s: Optional[jnp.ndarray] = None, *,
-          interpret: bool = True) -> jnp.ndarray:
+          interpret: Optional[bool] = None) -> jnp.ndarray:
     """Weighted mean of (m, d) rows via the single-pass combine kernel."""
     if s is None:
         s = jnp.ones((x.shape[0],), jnp.float32)
@@ -46,7 +47,7 @@ def wmean(x: jnp.ndarray, s: Optional[jnp.ndarray] = None, *,
 
 
 def wcwmed(x: jnp.ndarray, s: Optional[jnp.ndarray] = None, *,
-           use_pallas: bool = True, interpret: bool = True) -> jnp.ndarray:
+           use_pallas: bool = True, interpret: Optional[bool] = None) -> jnp.ndarray:
     """Weighted coordinate-wise median of (m, d) rows."""
     if s is None:
         s = jnp.ones((x.shape[0],), jnp.float32)
@@ -57,7 +58,7 @@ def wcwmed(x: jnp.ndarray, s: Optional[jnp.ndarray] = None, *,
 
 @partial(jax.jit, static_argnames=("iters", "eps", "interpret"))
 def _wgm_pallas(x: jnp.ndarray, s: jnp.ndarray, *, iters: int, eps: float,
-                interpret: bool) -> jnp.ndarray:
+                interpret: Optional[bool]) -> jnp.ndarray:
     """ω-GM: wcwmed anchor + ``iters`` fused Weiszfeld steps.
 
     X is padded ONCE (pad.py) and the fused dist+reweight+combine kernel is
@@ -75,7 +76,7 @@ def _wgm_pallas(x: jnp.ndarray, s: jnp.ndarray, *, iters: int, eps: float,
 
 
 def wgm(x: jnp.ndarray, s: Optional[jnp.ndarray] = None, *, iters: int = 8,
-        eps: float = 1e-8, use_pallas: bool = True, interpret: bool = True) -> jnp.ndarray:
+        eps: float = 1e-8, use_pallas: bool = True, interpret: Optional[bool] = None) -> jnp.ndarray:
     """ω-GM via Weiszfeld: fused kernelized distance+reweight+combine loop."""
     if s is None:
         s = jnp.ones((x.shape[0],), jnp.float32)
@@ -85,7 +86,7 @@ def wgm(x: jnp.ndarray, s: Optional[jnp.ndarray] = None, *, iters: int = 8,
 
 
 def wctma(x: jnp.ndarray, s: Optional[jnp.ndarray] = None, *, lam: float,
-          use_pallas: bool = True, interpret: bool = True,
+          use_pallas: bool = True, interpret: Optional[bool] = None,
           fused: bool = True) -> jnp.ndarray:
     """ω-CTMA (Alg. 1). ``fused=True`` (default) computes anchor + distances
     in one grid sweep (2 total HBM passes over X); ``fused=False`` keeps the
@@ -104,7 +105,7 @@ def wctma(x: jnp.ndarray, s: Optional[jnp.ndarray] = None, *, lam: float,
 
 @partial(jax.jit, static_argnames=("lam", "iters", "interpret"))
 def _wctma_gm_pallas(x: jnp.ndarray, s: jnp.ndarray, *, lam: float,
-                     iters: int = 32, interpret: bool) -> jnp.ndarray:
+                     iters: int = 32, interpret: Optional[bool]) -> jnp.ndarray:
     """ω-CTMA with a GM anchor: shares one padded copy of X across the GM
     loop, the anchor-distance pass and the trimmed combine."""
     xp, d, bd = pad_cols(x, FUSED_BLOCK_D)
@@ -122,7 +123,7 @@ def _wctma_gm_pallas(x: jnp.ndarray, s: jnp.ndarray, *, lam: float,
 
 
 def wctma_gm(x: jnp.ndarray, s: Optional[jnp.ndarray] = None, *, lam: float,
-             iters: int = 32, interpret: bool = True) -> jnp.ndarray:
+             iters: int = 32, interpret: Optional[bool] = None) -> jnp.ndarray:
     """ω-CTMA anchored at the weighted geometric median (shared padded X)."""
     if s is None:
         s = jnp.ones((x.shape[0],), jnp.float32)
@@ -130,7 +131,7 @@ def wctma_gm(x: jnp.ndarray, s: Optional[jnp.ndarray] = None, *, lam: float,
 
 
 def make_kernel_aggregator(spec: str, lam: float = 0.0, *,
-                           interpret: bool = True
+                           interpret: Optional[bool] = None
                            ) -> Callable[[jnp.ndarray, Optional[jnp.ndarray]], jnp.ndarray]:
     """Deprecated: use ``repro.agg.resolve(spec, backend="pallas")`` — the
     resolved callable also accepts stacked pytrees, and rules without a fused
@@ -143,7 +144,7 @@ def make_kernel_aggregator(spec: str, lam: float = 0.0, *,
 
 
 def swa_decode(q, k_cache, v_cache, pos, *, local: bool,
-               use_pallas: bool = True, interpret: bool = True):
+               use_pallas: bool = True, interpret: Optional[bool] = None):
     """Flash single-token decode over a (ring) KV cache; ``pos`` scalar or
     (B,) per-slot."""
     if not use_pallas:
@@ -152,7 +153,7 @@ def swa_decode(q, k_cache, v_cache, pos, *, local: bool,
 
 
 def paged_decode(q, k_pool, v_pool, page_table, pos, *,
-                 use_pallas: bool = True, interpret: bool = True):
+                 use_pallas: bool = True, interpret: Optional[bool] = None):
     """Per-slot paged flash decode over a block-table KV page pool (global
     causal layers; see serve/cache.py for the pool/table layout)."""
     if not use_pallas:
@@ -163,7 +164,7 @@ def paged_decode(q, k_pool, v_pool, page_table, pos, *,
 
 def ragged_paged_decode(q, k_pool, v_pool, page_table, cu_q_lens, q_lens,
                         kv_lens, *, use_pallas: bool = True,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """Ragged paged attention over a mixed chunked-prefill/decode batch: row
     ``s`` owns packed q tokens ``[cu_q_lens[s], cu_q_lens[s] + q_lens[s])``
     at context depth ``kv_lens[s]`` (see kernels/swa.py for the contract)."""
@@ -176,7 +177,7 @@ def ragged_paged_decode(q, k_pool, v_pool, page_table, cu_q_lens, q_lens,
 
 
 def ssd_scan(x, dt, A, Bm, Cm, chunk: int, *, use_pallas: bool = True,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """Mamba-2 SSD scan: Pallas intra-chunk kernel + XLA inter-chunk
     recurrence. Semantics identical to models.ssm.ssd_chunked."""
     if not use_pallas:

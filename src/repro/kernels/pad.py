@@ -20,12 +20,12 @@ def pad_cols(x: jnp.ndarray, block_d: int) -> tuple[jnp.ndarray, int, int]:
     """Pad the last axis of ``x`` up to a multiple of ``block_d`` with zeros.
 
     Returns ``(padded, d, bd)`` where ``d`` is the original size and ``bd`` the
-    effective tile (``min(block_d, d)``). No copy is made when d already tiles.
+    effective tile (``min(block_d, d)``). No copy is made when d already tiles:
+    the dtype is kept, and every kernel upcasts its tile to float32 in VMEM.
     """
     d = x.shape[-1]
     bd = min(block_d, d)
     pad = (-d) % bd
-    x = x.astype(jnp.float32)
     if pad:
         width = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
         x = jnp.pad(x, width)

@@ -14,10 +14,13 @@ per-program slabs keep everything under ~4 MiB.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .backend import interpret_mode
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_ref):
@@ -48,7 +51,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_intra_pallas(x, dt, A, Bm, Cm, *, chunk: int, interpret: bool = True):
+def ssd_intra_pallas(x, dt, A, Bm, Cm, *, chunk: int, interpret: Optional[bool] = None):
     """Intra-chunk SSD. x: (b, s, h, p); dt: (b, s, h); A: (h,);
     Bm/Cm: (b, s, n). s must divide by `chunk`.
     Returns (y_diag (b, s, h, p), states (b, nc, h, p, n), chunk_decay (b, nc, h))."""
@@ -80,7 +83,7 @@ def ssd_intra_pallas(x, dt, A, Bm, Cm, *, chunk: int, interpret: bool = True):
             jax.ShapeDtypeStruct((b * nc, c, h, p), jnp.float32),
             jax.ShapeDtypeStruct((b * nc, h, p, n), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xc, dtc, A[None, :], Bc, Cc)
 
     a = (dt * A[None, None, :]).reshape(b, nc, c, h)

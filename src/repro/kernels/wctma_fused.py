@@ -25,13 +25,17 @@ d is not a tile multiple) happens once for both passes (see pad.py).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .backend import interpret_mode
 
 from .pad import pad_cols
-from .wcwmed import wmed_tile
+from .wcwmed import weight_operands, wmed_tile
 from .wreduce import wcomb_padded
 
 # Wider tiles than the standalone median kernel: both fused passes are
@@ -40,12 +44,11 @@ from .wreduce import wcomb_padded
 DEFAULT_BLOCK_D = 2048
 
 
-def _anchor_dist_kernel(x_ref, s_ref, anchor_ref, dist_ref, *, m: int):
+def _anchor_dist_kernel(x_ref, s_ref, ss_ref, anchor_ref, dist_ref, *, m: int):
     j = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)          # (m, bd)
-    s = s_ref[...].astype(jnp.float32)          # (m, 1)
 
-    med = wmed_tile(x, s, m)                    # (bd,) anchor for this tile
+    med = wmed_tile(x, s_ref[...], ss_ref, m)   # (bd,) anchor for this tile
     anchor_ref[...] = med
 
     part = jnp.sum(jnp.square(x - med[None, :]), axis=1, keepdims=True)
@@ -58,7 +61,7 @@ def _anchor_dist_kernel(x_ref, s_ref, anchor_ref, dist_ref, *, m: int):
 
 
 def wctma_anchor_dist(xp: jnp.ndarray, s: jnp.ndarray, bd: int, *,
-                      interpret: bool = True) -> tuple[jnp.ndarray, jnp.ndarray]:
+                      interpret: Optional[bool] = None) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Single sweep over a pre-padded (m, dp) matrix returning
     (anchor (dp,), squared distances (m,))."""
     m, dp = xp.shape
@@ -68,6 +71,7 @@ def wctma_anchor_dist(xp: jnp.ndarray, s: jnp.ndarray, bd: int, *,
         in_specs=[
             pl.BlockSpec((m, bd), lambda j: (0, j)),
             pl.BlockSpec((m, 1), lambda j: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((bd,), lambda j: (j,)),
@@ -77,8 +81,8 @@ def wctma_anchor_dist(xp: jnp.ndarray, s: jnp.ndarray, bd: int, *,
             jax.ShapeDtypeStruct((dp,), jnp.float32),
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(xp, s.astype(jnp.float32)[:, None])
+        interpret=interpret_mode(interpret),
+    )(xp, *weight_operands(s))
     return anchor, dist[:, 0]
 
 
@@ -101,7 +105,7 @@ def trim_weights(dist: jnp.ndarray, s: jnp.ndarray, lam: float
 
 @functools.partial(jax.jit, static_argnames=("lam", "block_d", "interpret"))
 def wctma_fused(x: jnp.ndarray, s: jnp.ndarray, *, lam: float,
-                block_d: int = DEFAULT_BLOCK_D, interpret: bool = True
+                block_d: int = DEFAULT_BLOCK_D, interpret: Optional[bool] = None
                 ) -> jnp.ndarray:
     """Fused ω-CTMA: x (m, d), s (m,) -> (d,) float32. ≡ ref.wctma_ref."""
     xp, d, bd = pad_cols(x, block_d)

@@ -14,17 +14,20 @@
   VMEM. One launch and zero host round-trips per iteration, vs two launches
   plus an (m,) device→trace round-trip for the unfused pipeline.
 
-All wrappers take a pre-padded (m, dp) float32 matrix via the ``*_padded``
+All wrappers take a pre-padded (m, dp) matrix via the ``*_padded``
 entry points (see pad.py — pad once, launch many) with thin padding wrappers
 kept for standalone use.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .backend import interpret_mode
 
 from .pad import pad_cols
 
@@ -49,7 +52,7 @@ def _sqdist_kernel(x_ref, y_ref, o_ref):
 
 
 def sqdist_padded(xp: jnp.ndarray, yp: jnp.ndarray, bd: int, *,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """xp: (m, dp) pre-padded, yp: (dp,) -> (m,) squared distances."""
     m, dp = xp.shape
     out = pl.pallas_call(
@@ -61,14 +64,14 @@ def sqdist_padded(xp: jnp.ndarray, yp: jnp.ndarray, bd: int, *,
         ],
         out_specs=pl.BlockSpec((m, 1), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp, yp.astype(jnp.float32)[None, :])
     return out[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def sqdist_pallas(x: jnp.ndarray, y: jnp.ndarray, *, block_d: int = DEFAULT_BLOCK_D,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """x: (m, d), y: (d,) -> (m,) squared distances (float32)."""
     xp, d, bd = pad_cols(x, block_d)
     yp, _, _ = pad_cols(y, bd)
@@ -86,7 +89,7 @@ def _wcomb_kernel(x_ref, c_ref, o_ref):
 
 
 def wcomb_padded(xp: jnp.ndarray, coef: jnp.ndarray, denom, bd: int, *,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """Σ_i coef_i xp_i / denom over a pre-padded (m, dp) matrix -> (dp,)."""
     m, dp = xp.shape
     out = pl.pallas_call(
@@ -98,14 +101,14 @@ def wcomb_padded(xp: jnp.ndarray, coef: jnp.ndarray, denom, bd: int, *,
         ],
         out_specs=pl.BlockSpec((bd,), lambda j: (j,)),
         out_shape=jax.ShapeDtypeStruct((dp,), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp, coef.astype(jnp.float32)[:, None])
     return out / denom
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def wcomb_pallas(x: jnp.ndarray, coef: jnp.ndarray, denom, *,
-                 block_d: int = DEFAULT_BLOCK_D, interpret: bool = True) -> jnp.ndarray:
+                 block_d: int = DEFAULT_BLOCK_D, interpret: Optional[bool] = None) -> jnp.ndarray:
     """Σ_i coef_i x_i / denom. x: (m, d), coef: (m,) -> (d,)."""
     xp, d, bd = pad_cols(x, block_d)
     return wcomb_padded(xp, coef, denom, bd, interpret=interpret)[:d]
@@ -140,7 +143,7 @@ def _gm_step_kernel(x_ref, s_ref, y_ref, o_ref, dist_ref, *, eps: float):
 
 
 def gm_step_padded(xp: jnp.ndarray, s: jnp.ndarray, y: jnp.ndarray, bd: int, *,
-                   eps: float = 1e-8, interpret: bool = True) -> jnp.ndarray:
+                   eps: float = 1e-8, interpret: Optional[bool] = None) -> jnp.ndarray:
     """One Weiszfeld iteration y -> Σ_i (s_i/‖x_i-y‖) x_i / Σ_i (s_i/‖x_i-y‖).
 
     xp: (m, dp) pre-padded, y: (dp,) -> (dp,). Shape-stable, so it is the
@@ -163,6 +166,6 @@ def gm_step_padded(xp: jnp.ndarray, s: jnp.ndarray, y: jnp.ndarray, bd: int, *,
             jax.ShapeDtypeStruct((dp,), jnp.float32),
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp, s.astype(jnp.float32)[:, None], y.astype(jnp.float32)[None, :])
     return y_new

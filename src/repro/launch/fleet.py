@@ -59,6 +59,8 @@ def main(argv=None) -> None:
                          "(per-scenario loss trajectories + engine.* device "
                          "metrics; summarize with python -m repro.launch.obs)")
     args = ap.parse_args(argv)
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
 
     from repro.fleet import (breakdown_matrix, matrix_scenarios,
                              run_scenarios)

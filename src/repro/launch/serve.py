@@ -42,7 +42,7 @@ from repro.core.attacks import LOGIT_ATTACKS, LogitAttackConfig
 from repro.models.lm import init_lm
 from repro.serve import (ReplicatedConfig, ReplicatedServeEngine, ServeConfig,
                          ServeEngine, synth_workload)
-from repro.utils import logger
+from repro.utils import enable_compile_cache, logger
 
 
 def _csv_ints(text: str):
@@ -125,6 +125,7 @@ def main(argv=None) -> dict:
                     help="with --obs-dir: host-side spans/rows only, keep "
                          "the jitted steps' uninstrumented HLO")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if not cfg.supports_decode():

@@ -22,7 +22,7 @@ from repro.data import lm_batches
 from repro.dist.steps import (RobustDPConfig, init_train_state, make_robust_train_step,
                               make_train_step)
 from repro.optim.mu2sgd import OptConfig
-from repro.utils import logger
+from repro.utils import enable_compile_cache, logger
 
 
 def main(argv=None) -> dict:
@@ -47,6 +47,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     opt_cfg = OptConfig(name=args.opt, lr=args.lr, gamma=0.1, beta=0.25)

@@ -53,10 +53,13 @@ class ModelConfig:
     dtype: str = "float32"       # parameter / activation dtype
     scan_layers: bool = True     # stack+scan homogeneous layer groups
     remat: bool = False          # activation checkpointing on each layer group
-    # Pallas kernel integration (TPU target; interpret=True on CPU)
-    use_pallas_decode: bool = False   # flash decode (kernels/swa.py) in attention_decode
-    use_pallas_ssm: bool = False      # SSD intra-chunk kernel (kernels/ssd.py)
-    pallas_interpret: bool = True     # False on real TPUs
+    # Pallas kernels: None lets the backend decide (kernels/backend.py) —
+    # Mosaic kernels on TPU, the jnp paths and the interpreter elsewhere
+    use_pallas_decode: Optional[bool] = None  # attention kernels (kernels/swa.py)
+    # SSD intra-chunk kernel (kernels/ssd.py): off unless asked for — Mosaic
+    # does not lower it yet (kernels/README.md)
+    use_pallas_ssm: bool = False
+    pallas_interpret: Optional[bool] = None
 
     # ------------------------------------------------------------------
     @property

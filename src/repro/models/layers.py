@@ -7,6 +7,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import kernels_on
+
 from .config import ModelConfig
 
 Array = jnp.ndarray
@@ -153,13 +155,14 @@ def attention_decode(p: dict, cfg: ModelConfig, x: Array, kind: str,
     else:
         k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
         v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
-    if cfg.use_pallas_decode and W % min(128, W) == 0:
+    if kernels_on(cfg.use_pallas_decode):
         # flash-decode kernel: streams the cache through VMEM once; handles
-        # scalar AND per-slot (B,) pos (the index map routes each row's pos)
+        # scalar AND per-slot (B,) pos (scalar-prefetched per row). Blocks
+        # of 128 rows, or the whole cache when W does not tile by 128
         from repro.kernels.swa import swa_decode_pallas
         out = swa_decode_pallas(q[:, 0], k_cache, v_cache, pos,
                                 local=(kind == "local"),
-                                block_w=min(128, W),
+                                block_w=128 if W % 128 == 0 else W,
                                 interpret=cfg.pallas_interpret)
         out = out.reshape(B, 1, -1).astype(x.dtype)
     else:
@@ -205,7 +208,7 @@ def attention_decode_paged(p: dict, cfg: ModelConfig, x: Array,
     off = pos % P
     k_pool = k_pool.at[phys, off].set(k[:, 0].astype(k_pool.dtype))
     v_pool = v_pool.at[phys, off].set(v[:, 0].astype(v_pool.dtype))
-    if cfg.use_pallas_decode:
+    if kernels_on(cfg.use_pallas_decode):
         from repro.kernels.swa import paged_decode_pallas
         out = paged_decode_pallas(q[:, 0], k_pool, v_pool, page_table, pos,
                                   interpret=cfg.pallas_interpret)

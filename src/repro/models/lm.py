@@ -21,6 +21,8 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import kernels_on
+
 from .config import ModelConfig
 from .griffin import init_lru_cache, init_rglru, rglru_block, rglru_decode
 from .layers import attention, attention_decode, attention_decode_paged, init_attention, init_mlp, make_mask, mlp, rms_norm, rope_angles, apply_rope, _qkv, _sdpa
@@ -358,7 +360,7 @@ def _attn_chunk(lp: dict, cfg: ModelConfig, kind: str, x: Array, kvc,
         off = ctx.positions % P
         k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
         v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
-        if cfg.use_pallas_decode:
+        if kernels_on(cfg.use_pallas_decode):
             from repro.kernels.swa import ragged_paged_decode_pallas
             cu = C * jnp.arange(Rn + 1, dtype=jnp.int32)
             out = ragged_paged_decode_pallas(

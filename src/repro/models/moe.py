@@ -13,11 +13,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # this container's 0.4.37 ships it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .config import ModelConfig
 from .layers import init_mlp, mlp
 
@@ -128,7 +123,7 @@ def moe_block(p: dict, cfg: ModelConfig, x: Array) -> tuple[Array, Array]:
         def dispatch_local(xt_l, idx_l, gate_l):
             return _dispatch(xt_l, idx_l, gate_l, E, C_l)
 
-        buf, st, sg, slot = _shard_map(
+        buf, st, sg, slot = jax.shard_map(
             dispatch_local, mesh=mesh,
             in_specs=(P(dpax, None), P(dpax, None), P(dpax, None)),
             out_specs=(P(None, dpax, None), P(dpax), P(dpax), P(dpax)),
@@ -151,7 +146,7 @@ def moe_block(p: dict, cfg: ModelConfig, x: Array) -> tuple[Array, Array]:
         def combine_local(eo_l, st_l, sg_l, slot_l):
             return _combine(eo_l, st_l, sg_l, slot_l, T_l)
 
-        out = _shard_map(
+        out = jax.shard_map(
             combine_local, mesh=mesh,
             in_specs=(P(None, dpax, None), P(dpax), P(dpax), P(dpax)),
             out_specs=P(dpax, None),

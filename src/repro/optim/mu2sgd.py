@@ -120,11 +120,16 @@ def server_step(cfg: OptConfig, state: OptState, d_hat: Pytree, lr_scale=1.0) ->
     alpha = (jnp.asarray(1.0, jnp.float32) if cfg.gamma is not None
              else t_next.astype(jnp.float32))
     step_size = cfg.lr * lr_scale * alpha
+    # every update is cast back to the state's dtype: the float32 step size
+    # would otherwise promote bf16 iterates to f32 after one step, which
+    # defeats donation and recompiles the step on the second call
     w_new = _tmap(lambda wl, dl: (wl - step_size * dl.astype(wl.dtype)
-                                  - cfg.lr * cfg.weight_decay * wl), state.w, d_hat)
+                                  - cfg.lr * cfg.weight_decay * wl
+                                  ).astype(wl.dtype), state.w, d_hat)
     w_new = _project(cfg, w_new, state.anchor)
     gcoef = anytime_coeff(t_next + 1, cfg.gamma)
-    x_new = _tmap(lambda xl, wl: xl + gcoef.astype(xl.dtype) * (wl - xl), state.x, w_new)
+    x_new = _tmap(lambda xl, wl: (xl + gcoef.astype(xl.dtype) * (wl - xl)
+                                  ).astype(xl.dtype), state.x, w_new)
     x_prev = None if cfg.implicit_x_prev else state.x
     return OptState(w=w_new, x=x_new, x_prev=x_prev, d=state.d, t=t_next,
                     anchor=state.anchor)

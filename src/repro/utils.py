@@ -5,6 +5,7 @@ XLA_FLAGS at import time and must never be imported just for the parser)."""
 from __future__ import annotations
 
 import logging
+import os
 import re
 import time
 from functools import partial
@@ -22,6 +23,29 @@ if not logger.handlers:
     _h.setFormatter(logging.Formatter("[%(asctime)s %(name)s] %(message)s", "%H:%M:%S"))
     logger.addHandler(_h)
     logger.setLevel(logging.INFO)
+
+
+# <repo>/.jax_cache: a fixed path (the cache key includes it), listed in
+# .gitignore
+_REPO_CACHE = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), ".jax_cache")
+
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives: the directory that
+    ``JAX_COMPILATION_CACHE_DIR`` names, else ``<repo>/.jax_cache``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _REPO_CACHE
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache; every entry point (the
+    launchers, ``benchmarks/run.py``, ``chip_smoke.py``) calls this once,
+    importing a module never does. With ``JAX_COMPILATION_CACHE_DIR`` set,
+    JAX already reads it and nothing else is set here."""
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def ravel_pytree_fn(tree: Pytree) -> tuple[jnp.ndarray, Callable[[jnp.ndarray], Pytree]]:

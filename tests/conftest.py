@@ -1,18 +1,8 @@
 # NOTE: deliberately NO global XLA_FLAGS here — smoke tests and benchmarks
 # must see the single real CPU device; only launch/dryrun.py (and the
 # subprocess tests that invoke it) force the 512-placeholder-device platform.
-import importlib.util
-
 import numpy as np
 import pytest
-
-# The property suites use `hypothesis` (see requirements-dev.txt). In offline
-# containers without it, fall back to the vendored minimal shim so the suites
-# still run; the real package is preferred whenever it is installed.
-if importlib.util.find_spec("hypothesis") is None:
-    from repro._hypothesis_fallback import install
-
-    install()
 
 
 @pytest.fixture(scope="session")

@@ -26,6 +26,7 @@ import pytest
 
 from repro.agg import resolve
 from repro.dist.context import mesh_context
+from repro.launch.mesh import auto_mesh
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -46,7 +47,7 @@ SPECS = [
 
 
 def _mesh():
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return auto_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 def _tree(m=6, seed=0):

@@ -35,6 +35,23 @@ def test_wcwmed_tie_handling(m, d):
                                np.median(np.asarray(x), axis=0), atol=1e-6)
 
 
+def test_wcwmed_near_tie_within_one_ulp():
+    """A prefix one ulp above S/2 is a tie for the oracle's relative
+    tolerance, so the kernel must average it with the next element too."""
+    s = np.array([0.5 + 2.0 ** -24, 0.25, 0.25 - 2.0 ** -24], np.float32)
+    assert s.sum(dtype=np.float32) == 1.0
+    assert np.nextafter(np.float32(0.5), np.float32(1.0)) == s[0]
+    x = np.asarray(jax.random.normal(jax.random.fold_in(KEY, 3), (3, 640)))
+    xs = np.sort(x, axis=0)                  # row 0 smallest: prefix = s[0]
+    for mat in (xs, x):
+        want = np.asarray(ref.wcwmed_ref(jnp.asarray(mat), jnp.asarray(s)))
+        got = np.asarray(ops.wcwmed(jnp.asarray(mat), jnp.asarray(s)))
+        np.testing.assert_allclose(got, want, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(ops.wcwmed(jnp.asarray(xs), jnp.asarray(s))),
+        0.5 * (xs[0] + xs[1]), atol=1e-6)
+
+
 @pytest.mark.parametrize("m,d", SHAPES_MD)
 def test_sqdist_and_wcomb(m, d):
     k1, k2, k3 = jax.random.split(jax.random.fold_in(KEY, 7 * m + d), 3)

@@ -22,7 +22,8 @@ cfg_s = cfg_d.with_(moe_dispatch="sharded")
 key = jax.random.PRNGKey(0)
 params = init_lm(key, cfg_d)
 toks = jax.random.randint(key, (4, 16), 0, 64)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((4, 2), ("data", "model"))
 ref, _ = forward(params, cfg_d, {"tokens": toks})
 with mesh, mesh_context(mesh):
     out, _ = jax.jit(lambda p, t: forward(p, cfg_s, {"tokens": t}))(params, toks)

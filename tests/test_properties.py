@@ -1,4 +1,4 @@
-"""Property tests (hypothesis, or the offline fallback shim from conftest):
+"""Property tests (hypothesis):
 the little-attack deviation bound and staleness vote masses."""
 import jax.numpy as jnp
 import numpy as np

@@ -1,8 +1,8 @@
 """Ragged paged attention: oracle vs dense per-request reference, and the
 Pallas kernel (interpret mode) vs the oracle.
 
-Property sweep (via the gated hypothesis shim — tests/conftest.py): arbitrary
-``cu_q_lens`` splits of a packed token batch, with q_len=1 decode rows,
+Property sweep (hypothesis): arbitrary ``cu_q_lens`` splits of a packed
+token batch, with q_len=1 decode rows,
 multi-token prefill chunks, EMPTY chunks, partial last pages, inter-row
 padding gaps and trailing padding, must all agree with a reference that never
 sees the packing at all — each request's pages gathered dense, sliced to its

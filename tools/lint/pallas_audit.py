@@ -40,6 +40,7 @@ ASSUMED_DIMS: Dict[str, int] = {
     "KV": 8, "G": 4, "H": 32, "hd": 128,
     "W": 4096,       # dense cache window
     "P": 16,         # page size (tokens per page)
+    "R": 864,        # ragged q rows per KV head: G·Tp, 6 × (8 + 1) slots·16
     "pps": 64,       # pages per slot
     "c": 64, "h": 8, "p": 64, "n": 64,   # SSD chunk/heads/head_dim/state
     "b": 4, "nc": 4,
