@@ -15,6 +15,10 @@ in place instead and agree leaf-for-leaf with ``core.aggregators``:
   resulting per-worker scalar weights are broadcast back into leaf-wise
   combines. No leaf is ever materialized twice.
 
+ω-CTMA names its passes ``anchor``, ``distance`` and ``combine``, ω-GM its
+start ``anchor`` and its iterations ``weiszfeld`` (``repro.obs.scopes``), so
+a device trace splits an aggregate by pass.
+
 HBM passes over the stacked tree X (d = total parameter count):
     stacked_mean    1     stacked_cwmed   1
     stacked_gm      1 + 2·iters (distance pass + reweighted combine per iter)
@@ -29,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.aggregators import weighted_cwmed, weighted_cwtm
+from repro.obs.scopes import ANCHOR, COMBINE, DISTANCE, WEISZFELD, phase
 
 Array = jnp.ndarray
 Pytree = Any
@@ -110,14 +115,16 @@ def stacked_gm(tree: Pytree, s: Optional[Array] = None, *, iters: int = 32,
                eps: float = 1e-8) -> Pytree:
     """ω-GM via Weiszfeld with the distance pass computed once globally."""
     s = _weights(s, _lead(tree))
-    y0 = stacked_cwmed(tree, s)
+    with phase(ANCHOR):
+        y0 = stacked_cwmed(tree, s)
 
     def body(_, y):
         dist = jnp.sqrt(jnp.maximum(stacked_sqdist(tree, y), 0.0))
         invd = s / jnp.maximum(dist, eps)
         return _combine(tree, invd, jnp.sum(invd))
 
-    return jax.lax.fori_loop(0, iters, body, y0)
+    with phase(WEISZFELD):
+        return jax.lax.fori_loop(0, iters, body, y0)
 
 
 def stacked_ctma(tree: Pytree, s: Optional[Array] = None, *, lam: float,
@@ -130,10 +137,14 @@ def stacked_ctma(tree: Pytree, s: Optional[Array] = None, *, lam: float,
 
     s = _weights(s, _lead(tree))
     if x0 is None:
-        x0 = base(tree, s)
-    # squared distances order identically to distances — skip the sqrt
-    kept, thresh = trim_weights(stacked_sqdist(tree, x0), s, lam)
-    return _combine(tree, kept, jnp.maximum(thresh, 1e-30))
+        with phase(ANCHOR):
+            x0 = base(tree, s)
+    with phase(DISTANCE):
+        # squared distances order identically to distances — skip the sqrt
+        d2 = stacked_sqdist(tree, x0)
+    with phase(COMBINE):
+        kept, thresh = trim_weights(d2, s, lam)
+        return _combine(tree, kept, jnp.maximum(thresh, 1e-30))
 
 
 def stacked_cwtm(tree: Pytree, s: Optional[Array] = None, *,

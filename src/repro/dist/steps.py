@@ -18,6 +18,10 @@ Byzantine group behaviors follow core.attacks (Appendix D), adapted to the
 group setting: label_flip poisons a group's labels before its gradients;
 sign_flip negates its transmitted momentum; little/empire are omniscient over
 the honest groups' stacked buffers and their weights.
+
+The robust step names its phases (``robust_step/grad_x``, ``grad_xprev``,
+``momentum``, ``attack``, ``aggregate``, ``update``; ``repro.obs.scopes``)
+so a device trace splits by phase; the names are metadata only.
 """
 from __future__ import annotations
 
@@ -29,6 +33,8 @@ import jax.numpy as jnp
 from repro.core.attacks import _little_zmax, flip_labels
 from repro.models.config import ModelConfig
 from repro.models.lm import chunk_step, decode_step, init_lm, lm_loss, prefill
+from repro.obs.scopes import (AGGREGATE, ATTACK, GRAD_X, GRAD_XPREV, MOMENTUM,
+                               UPDATE, phase)
 from repro.optim.mu2sgd import (OptConfig, OptState, _project, init_opt,
                                 opt_query_points, opt_update, server_step)
 from repro.utils import global_norm
@@ -208,21 +214,24 @@ def make_robust_train_step(cfg: ModelConfig, opt_cfg: OptConfig,
         return lm_loss(params, cfg, batch)
 
     def per_group(xq, xprev, gbatch, flip):
-        if label_flip_on:
-            lab = gbatch["labels"]
-            lab = jnp.where(flip, flip_labels(lab, cfg.vocab), lab)
-            gbatch = {**gbatch, "labels": lab}
-        loss, g = jax.value_and_grad(loss_fn)(xq, gbatch)
-        g_tilde = (jax.grad(loss_fn)(xprev, gbatch)
-                   if opt_cfg.name == "mu2" else g)
-        return loss, g, g_tilde
+        with phase(GRAD_X):
+            if label_flip_on:
+                lab = gbatch["labels"]
+                lab = jnp.where(flip, flip_labels(lab, cfg.vocab), lab)
+                gbatch = {**gbatch, "labels": lab}
+            loss, g = jax.value_and_grad(loss_fn)(xq, gbatch)
+        if opt_cfg.name != "mu2":
+            return loss, g, g
+        with phase(GRAD_XPREV):
+            return loss, g, jax.grad(loss_fn)(xprev, gbatch)
 
     def step(state: TrainState, batch: dict):
         opt = state.opt
         B = jax.tree_util.tree_leaves(batch)[0].shape[0]
         sizes = _group_sizes(rcfg, B)
         flip_flags = jnp.asarray([i in byz_list for i in range(G)])
-        xq, xprev = opt_query_points(opt_cfg, opt)
+        with phase(GRAD_XPREV):         # x_{t-1}, recomputed if implicit
+            xq, xprev = opt_query_points(opt_cfg, opt)
 
         if len(set(sizes)) == 1:
             # uniform groups: ONE traced gradient, vmapped over the group axis
@@ -233,55 +242,64 @@ def make_robust_train_step(cfg: ModelConfig, opt_cfg: OptConfig,
             outs = []
             off = 0
             for i, sz in enumerate(sizes):
-                gbatch = _tmap(lambda v: jax.lax.slice_in_dim(v, off, off + sz), batch)
+                with phase(GRAD_X):
+                    gbatch = _tmap(lambda v: jax.lax.slice_in_dim(
+                        v, off, off + sz), batch)
                 outs.append(per_group(xq, xprev, gbatch, flip_flags[i]))
                 off += sz
-            losses = jnp.stack([o[0] for o in outs])
-            g = _stack_trees([o[1] for o in outs])
-            g_tilde = _stack_trees([o[2] for o in outs])
-
-        counts_new = state.counts + 1.0
+            with phase(GRAD_X):
+                losses = jnp.stack([o[0] for o in outs])
+                g = _stack_trees([o[1] for o in outs])
+            with phase(GRAD_XPREV):
+                g_tilde = _stack_trees([o[2] for o in outs])
 
         # per-group corrected momentum (μ²) / Polyak momentum / raw gradient
-        if opt_cfg.name == "mu2":
-            beta = (jnp.full((G,), opt_cfg.beta, jnp.float32)
-                    if opt_cfg.beta is not None
-                    else 1.0 / jnp.maximum(counts_new, 1.0))
-            first = counts_new <= 1.0
+        with phase(MOMENTUM):
+            counts_new = state.counts + 1.0
+            if opt_cfg.name == "mu2":
+                beta = (jnp.full((G,), opt_cfg.beta, jnp.float32)
+                        if opt_cfg.beta is not None
+                        else 1.0 / jnp.maximum(counts_new, 1.0))
+                first = counts_new <= 1.0
 
-            def corr(gl, dl, gtl):
-                b = _bcast(beta, gl)
-                upd = gl.astype(jnp.float32) + (1.0 - b) * (
-                    dl.astype(jnp.float32) - gtl.astype(jnp.float32))
-                return jnp.where(_bcast(first.astype(jnp.float32), gl) > 0,
-                                 gl.astype(jnp.float32), upd).astype(dl.dtype)
+                def corr(gl, dl, gtl):
+                    b = _bcast(beta, gl)
+                    upd = gl.astype(jnp.float32) + (1.0 - b) * (
+                        dl.astype(jnp.float32) - gtl.astype(jnp.float32))
+                    return jnp.where(
+                        _bcast(first.astype(jnp.float32), gl) > 0,
+                        gl.astype(jnp.float32), upd).astype(dl.dtype)
 
-            D_new = _tmap(corr, g, state.D, g_tilde)
-        elif opt_cfg.name == "momentum":
-            beta = 0.9 if opt_cfg.beta is None else opt_cfg.beta
-            D_new = _tmap(lambda dl, gl: (beta * dl.astype(jnp.float32)
-                                          + (1.0 - beta) * gl.astype(jnp.float32)
-                                          ).astype(dl.dtype), state.D, g)
-        else:  # sgd
-            D_new = _tmap(lambda dl, gl: gl.astype(dl.dtype), state.D, g)
+                D_new = _tmap(corr, g, state.D, g_tilde)
+            elif opt_cfg.name == "momentum":
+                beta = 0.9 if opt_cfg.beta is None else opt_cfg.beta
+                D_new = _tmap(lambda dl, gl: (
+                    beta * dl.astype(jnp.float32)
+                    + (1.0 - beta) * gl.astype(jnp.float32)).astype(dl.dtype),
+                    state.D, g)
+            else:  # sgd
+                D_new = _tmap(lambda dl, gl: gl.astype(dl.dtype), state.D, g)
 
         size_w = jnp.asarray(sizes, jnp.float32)
         weights = counts_new if rcfg.weight_mode == "counts" else size_w
 
-        D_new = _apply_byz_attacks(rcfg, D_new, weights)
+        with phase(ATTACK):
+            D_new = _apply_byz_attacks(rcfg, D_new, weights)
 
-        d_hat = agg_fn(D_new, weights)
+        with phase(AGGREGATE):
+            d_hat = agg_fn(D_new, weights)
 
-        if opt_cfg.name == "mu2":
-            new_opt = server_step(opt_cfg, opt, d_hat)
-        else:
-            # same decoupled weight decay as opt_update/server_step
-            w = _tmap(lambda wl, dl: (wl - opt_cfg.lr * dl.astype(wl.dtype)
-                                      - opt_cfg.lr * opt_cfg.weight_decay * wl),
-                      opt.w, d_hat)
-            w = _project(opt_cfg, w, opt.anchor)
-            new_opt = OptState(w=w, x=w, x_prev=None, d=opt.d, t=opt.t + 1,
-                               anchor=opt.anchor)
+        with phase(UPDATE):
+            if opt_cfg.name == "mu2":
+                new_opt = server_step(opt_cfg, opt, d_hat)
+            else:
+                # same decoupled weight decay as opt_update/server_step
+                w = _tmap(lambda wl, dl: (
+                    wl - opt_cfg.lr * dl.astype(wl.dtype)
+                    - opt_cfg.lr * opt_cfg.weight_decay * wl), opt.w, d_hat)
+                w = _project(opt_cfg, w, opt.anchor)
+                new_opt = OptState(w=w, x=w, x_prev=None, d=opt.d,
+                                   t=opt.t + 1, anchor=opt.anchor)
 
         loss = jnp.sum(losses * size_w) / jnp.sum(size_w)
         metrics = {"loss": loss, "grad_norm": global_norm(d_hat)}

@@ -83,6 +83,7 @@ def ssd_intra_pallas(x, dt, A, Bm, Cm, *, chunk: int, interpret: Optional[bool] 
             jax.ShapeDtypeStruct((b * nc, c, h, p), jnp.float32),
             jax.ShapeDtypeStruct((b * nc, h, p, n), jnp.float32),
         ],
+        name="ssd_intra",
         interpret=interpret_mode(interpret),
     )(xc, dtc, A[None, :], Bc, Cc)
 

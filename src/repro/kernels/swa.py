@@ -145,6 +145,7 @@ def swa_decode_pallas(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray
         functools.partial(_kernel, W=W, bw=bw, KV=KV, local=local),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, hd), jnp.float32),
+        name="swa_decode",
         interpret=interpret_mode(interpret),
     )(pos_arr, qg, kg, vg)
     return out.reshape(B, H, hd)
@@ -213,6 +214,7 @@ def paged_decode_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
         functools.partial(_paged_kernel, P=P, KV=KV),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S * KV, G, hd), jnp.float32),
+        name="paged_decode",
         interpret=interpret_mode(interpret),
     )(tbl, pos_arr, qg, _head_pages(k_pool), _head_pages(v_pool))
     return out.reshape(S, H, hd)
@@ -332,6 +334,7 @@ def ragged_paged_decode_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
         functools.partial(_ragged_kernel, P=P),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KV, R, hd), jnp.float32),
+        name="ragged_paged_attention",
         interpret=interpret_mode(interpret),
     )(jnp.asarray(cu_q_lens, jnp.int32), jnp.asarray(q_lens, jnp.int32),
       jnp.asarray(kv_lens, jnp.int32), jnp.asarray(page_table, jnp.int32),

@@ -81,6 +81,7 @@ def wctma_anchor_dist(xp: jnp.ndarray, s: jnp.ndarray, bd: int, *,
             jax.ShapeDtypeStruct((dp,), jnp.float32),
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
+        name="wctma_anchor_dist",
         interpret=interpret_mode(interpret),
     )(xp, *weight_operands(s))
     return anchor, dist[:, 0]

@@ -104,6 +104,7 @@ def wcwmed_padded(xp: jnp.ndarray, s: jnp.ndarray, bd: int, *,
         ],
         out_specs=pl.BlockSpec((bd,), lambda j: (j,)),
         out_shape=jax.ShapeDtypeStruct((dp,), jnp.float32),
+        name="wcwmed",
         interpret=interpret_mode(interpret),
     )(xp, *weight_operands(s))
 

@@ -64,6 +64,7 @@ def sqdist_padded(xp: jnp.ndarray, yp: jnp.ndarray, bd: int, *,
         ],
         out_specs=pl.BlockSpec((m, 1), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
+        name="sqdist",
         interpret=interpret_mode(interpret),
     )(xp, yp.astype(jnp.float32)[None, :])
     return out[:, 0]
@@ -101,6 +102,7 @@ def wcomb_padded(xp: jnp.ndarray, coef: jnp.ndarray, denom, bd: int, *,
         ],
         out_specs=pl.BlockSpec((bd,), lambda j: (j,)),
         out_shape=jax.ShapeDtypeStruct((dp,), jnp.float32),
+        name="wcomb",
         interpret=interpret_mode(interpret),
     )(xp, coef.astype(jnp.float32)[:, None])
     return out / denom
@@ -166,6 +168,7 @@ def gm_step_padded(xp: jnp.ndarray, s: jnp.ndarray, y: jnp.ndarray, bd: int, *,
             jax.ShapeDtypeStruct((dp,), jnp.float32),
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
+        name="gm_step",
         interpret=interpret_mode(interpret),
     )(xp, s.astype(jnp.float32)[:, None], y.astype(jnp.float32)[None, :])
     return y_new

@@ -7,7 +7,9 @@ Collects timeline events while an engine runs — complete spans
 (``ph="b"``/``"e"`` keyed by request uid) — and exports them as the Chrome
 trace-event JSON Perfetto loads directly (``ui.perfetto.dev`` → open file).
 Timestamps are microseconds from tracer construction on
-``time.perf_counter``.
+``time.perf_counter``. Each span is also a ``jax.profiler.TraceAnnotation``
+of the same name: while a profile is being taken (``jax.profiler.trace``)
+it lands on the profile's host plane, on the clock of the device's ops.
 
 XLA compiles are folded in as first-class trace events:
 :meth:`Tracer.attach_compile_events` registers a ``jax.monitoring``
@@ -29,6 +31,8 @@ import time
 from contextlib import contextmanager
 from pathlib import Path
 from typing import Any, Dict, Iterator, List, Optional, Union
+
+from jax.profiler import TraceAnnotation
 
 from repro.lint_runtime import (BACKEND_COMPILE_EVENT, TRACE_EVENT,
                                 _unregister)
@@ -70,10 +74,12 @@ class Tracer:
     @contextmanager
     def span(self, name: str, cat: str = "engine", tid: int = TID_ENGINE,
              **args: Any) -> Iterator[None]:
-        """Complete event around a block of work."""
+        """Complete event around a block of work, and the profiler's
+        annotation of it (a no-op unless a profile is being taken)."""
         ts = self.now_us()
         try:
-            yield
+            with TraceAnnotation(name):
+                yield
         finally:
             self._push({"name": name, "cat": cat, "ph": "X", "ts": ts,
                         "dur": self.now_us() - ts, "pid": self.pid,

@@ -4,12 +4,16 @@ Mosaic, not the interpreter: each case lowers with ``interpret=False``
 against a described (not attached) ``v5e:2x2`` topology and must produce a
 ``tpu_custom_call``. This catches what interpret mode never checks — block
 shapes that do not tile (8, 128), ops Mosaic cannot lower, VMEM overruns.
+Each aggregation kernel's custom call must also keep the name the
+benchmark's trace readers match, under any enclosing scope.
 
 The topology is described only inside the module fixture: describing it
 loads libtpu, which one process at a time may hold, so it must not happen
 while any module is imported. The persistent compilation cache is off here:
 an entry written for a described chip cannot be read back without one.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -101,3 +105,30 @@ AGG_KERNELS = {
 def test_aggregation_kernel_compiles(shape, name):
     _mosaic(AGG_KERNELS[name], shape((M, D), f32), shape((M,), f32),
             shape((D,), f32))
+
+
+# what the benchmark's trace readers match in a kernel's op name: the
+# server's aggregation kernels (bench/metrics/agg_kernels_roofline.server.py)
+# and, for the median, the train step's (cwmed_kernel_roofline.train.py)
+SERVER_MATCH = r"wcwmed|wctma|anchor_dist|wcomb|sqdist|gm_step"
+READER_MATCH = {"wcwmed": "wcwmed", "wctma_fused": "wctma_anchor_dist|wcomb",
+                "gm_step": "gm_step", "sqdist": "sqdist", "wcomb": "wcomb"}
+
+
+@pytest.mark.parametrize("name", sorted(AGG_KERNELS))
+def test_aggregation_kernel_custom_call_keeps_its_name(shape, name):
+    """Traced inside a phase scope, each kernel's custom call is still named
+    with the substring its reader matches: the name comes from the kernel's
+    ``pallas_call(name=...)``, not from the scope or jit around it."""
+    from repro.obs.scopes import AGGREGATE, phase
+
+    def scoped(*a):
+        with phase(AGGREGATE):
+            return AGG_KERNELS[name](*a)
+
+    hlo = jax.jit(scoped).lower(shape((M, D), f32), shape((M,), f32),
+                                shape((D,), f32)).compile().as_text()
+    calls = [l.split(" = ")[0].split()[-1].lstrip("%")
+             for l in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    assert calls and all(re.match(READER_MATCH[name], c) for c in calls), calls
+    assert all(re.search(SERVER_MATCH, c) for c in calls), calls

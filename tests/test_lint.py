@@ -24,7 +24,7 @@ from lint.engine import ModuleUnderLint
 
 FIXTURES = ROOT / "tools" / "lint" / "fixtures"
 AST_CODES = ["RL101", "RL102", "RL103", "RL104", "RL105"]
-PALLAS_CODES = ["RP301", "RP302", "RP303"]
+PALLAS_CODES = ["RP301", "RP302", "RP303", "RP304"]
 
 
 # ---------------------------------------------------------------------------

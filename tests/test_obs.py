@@ -168,6 +168,25 @@ def test_tracer_exports_valid_chrome_trace(tmp_path):
     assert {"X", "i", "C", "b", "e", "M"} <= phases
 
 
+def test_tracer_span_lands_on_the_profile_host_plane(tmp_path):
+    """A span taken while the profiler runs is on the profile's host plane,
+    on the clock of the device's ops, and still in the tracer's own export."""
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("obs.test_span"):
+            jnp.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(str(sorted(tmp_path.rglob("*.xplane.pb"))[-1]))
+    host = {ev.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert "obs.test_span" in host
+    assert [e["name"] for e in tr.export()["traceEvents"]
+            if e["ph"] == "X"] == ["obs.test_span"]
+
+
 # ---------------------------------------------------------------------------
 # RunObs: the one handle the engines take
 # ---------------------------------------------------------------------------

@@ -7,8 +7,8 @@
     python tools/lint.py --write-kernel-table           # refresh kernels/README.md
     python tools/lint.py --check-kernel-table           # CI drift gate
 
-Groups: ``ast`` (RL101–RL105 JAX hazards), ``pallas`` (RP301–RP303 kernel
-VMEM/grid audit + generated VMEM table), ``docs`` (RD201/RD202, the folded
+Groups: ``ast`` (RL101–RL105 JAX hazards), ``pallas`` (RP301–RP304 kernel
+VMEM/grid/naming audit + generated VMEM table), ``docs`` (RD201/RD202, the folded
 ``tools/docs_check.py``). Rule catalog: ``tools/lint/README.md``.
 """
 from __future__ import annotations

@@ -3,7 +3,7 @@
 Rule groups (select with ``--only``):
 
 - ``ast``    — RL101–RL105 JAX hazard rules (:mod:`tools.lint.rules_ast`)
-- ``pallas`` — RP301–RP303 kernel VMEM/grid audit (:mod:`tools.lint.pallas_audit`)
+- ``pallas`` — RP301–RP304 kernel VMEM/grid/naming audit (:mod:`tools.lint.pallas_audit`)
 - ``docs``   — RD201/RD202 markdown links + module docstrings, RD203 obs
   metric-catalog coverage (:mod:`tools.lint.docs_rules`, absorbed from
   ``tools/docs_check.py``)

@@ -18,4 +18,5 @@ def huge_block(x):
         in_specs=[pl.BlockSpec((HUGE, HUGE), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((HUGE, HUGE), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((HUGE, HUGE), jnp.float32),
+        name="huge_copy",
     )(x)

@@ -19,4 +19,5 @@ def tiled_copy(x):
         in_specs=[pl.BlockSpec((TILE, TILE), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((TILE, TILE), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((HUGE, HUGE), jnp.float32),
+        name="tiled_copy",
     )(x)

@@ -18,4 +18,5 @@ def bad_arity(x):
         in_specs=[pl.BlockSpec((TILE, TILE), lambda i: (i, 0))],   # 1 arg
         out_specs=pl.BlockSpec((TILE, TILE), lambda i, j: (i,)),   # 1 index
         out_shape=jax.ShapeDtypeStruct((N, N), jnp.float32),
+        name="bad_arity_copy",
     )(x)

@@ -19,4 +19,5 @@ def good_arity(x):
         in_specs=[pl.BlockSpec((TILE, TILE), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((TILE, TILE), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, N), jnp.float32),
+        name="good_arity_copy",
     )(x)

@@ -1,10 +1,11 @@
-"""replint Pallas auditor RP301–RP303: static VMEM + grid checks on kernels.
+"""replint Pallas auditor RP301–RP304: static VMEM, grid and naming checks on kernels.
 
 | code  | invariant                                                          |
 |-------|--------------------------------------------------------------------|
 | RP301 | per-kernel VMEM footprint (in + out blocks + scratch) over budget  |
 | RP302 | BlockSpec index-map arity ≠ grid rank (+ scalar-prefetch count), or index-map return rank ≠ block rank |
 | RP303 | paged pool allocated without the reserved dump page (``n_pages`` where ``n_pages + 1`` is required) |
+| RP304 | ``pallas_call`` without ``name=``: its custom call would be named after whatever jit or scope encloses it |
 
 VMEM accounting: every ``pl.pallas_call`` site is parsed from the AST; each
 ``pl.BlockSpec`` block shape and ``pltpu.VMEM`` scratch shape is evaluated
@@ -319,6 +320,13 @@ def audit_module(mod: ModuleUnderLint,
                         numel *= max(d, 1)
                     nbytes = numel * _dtype_bytes(dtype_node)
                 blocks.append(BlockInfo(f"scratch[{i}]", shape, nbytes))
+
+        if _kw(call, "name") is None:
+            findings.append(Finding(
+                "RP304", mod.path, call.lineno,
+                "pallas_call without name= — the custom call takes the name "
+                "of whatever jit or named scope encloses it, and trace "
+                "readers that match kernels by name lose it"))
 
         site = KernelSite(mod.path, call.lineno,
                           _enclosing_func_name(mod, call),
