@@ -277,13 +277,14 @@ def _cwtm_lam(sp: AggregatorSpec) -> float:
 
 
 def _stacked_cwmed(sp: AggregatorSpec) -> Callable:
-    """Leaf-wise ω-CWMed; on the pallas backend each leaf runs the median
-    kernel, which reads the leaf in its own dtype and keeps its selection in
-    VMEM (the jnp oracle's sort materializes several f32 copies of every
-    leaf — more than a chip holds for a 10^8-element embedding)."""
+    """Leaf-wise ω-CWMed; on the pallas backend each leaf runs the leaf
+    median kernel, which reads the leaf in its own dtype and layout and keeps
+    its selection in VMEM (the jnp oracle's sort materializes several f32
+    copies of every leaf — more than a chip holds for a 10^8-element
+    embedding)."""
     if _backend(sp) == "pallas":
         return partial(_stk().stacked_cwmed,
-                       median=partial(_ops().wcwmed, interpret=_interp(sp)))
+                       median=partial(_ops().wcwmed_leaf, interpret=_interp(sp)))
     return _stk().stacked_cwmed
 
 

@@ -9,6 +9,8 @@ multi-pod dry-run lowers.
 HBM-pass accounting for the (m, d) update matrix X (see wctma_fused.py):
 
     wcwmed          1 pass
+    wcwmed_leaf     1 pass over one (m, *shape) leaf of a stacked tree, in
+                    its own layout (no flattened copy)
     wgm             1 (anchor) + 2·iters (fused dist+combine step), ONE traced
                     loop body via lax.fori_loop — previously the python loop
                     unrolled 2·iters separate pallas_call launches (and a pad
@@ -27,7 +29,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .pad import pad_cols
-from .wcwmed import wcwmed_pallas, wcwmed_padded
+from .wcwmed import wcwmed_leaf, wcwmed_pallas, wcwmed_padded
 from .wreduce import gm_step_padded, sqdist_pallas, wcomb_padded, wcomb_pallas
 from .wctma_fused import (DEFAULT_BLOCK_D as FUSED_BLOCK_D, trim_weights,
                           wctma_fused)

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, wcwmed
 from repro.kernels.wctma_fused import wctma_fused
 from repro.kernels.wreduce import sqdist_pallas, wcomb_pallas
 
@@ -50,6 +50,118 @@ def test_wcwmed_near_tie_within_one_ulp():
     np.testing.assert_allclose(
         np.asarray(ops.wcwmed(jnp.asarray(xs), jnp.asarray(s))),
         0.5 * (xs[0] + xs[1]), atol=1e-6)
+
+
+def _leaf_shape(rank, m, dtype, c=256):
+    """A leaf (m, *shape) of the given rank whose rows R overrun the leaf
+    kernel's row block and are no multiple of it: the last block is partial."""
+    if rank == 2:
+        return (m, 3 * c)
+    br = wcwmed._leaf_block(m, 1 << 30, c, jnp.dtype(dtype).itemsize)[0]
+    rows = br + 24
+    assert rows % br and rows > br
+    return (m, rows, c) if rank == 3 else (m, 2, rows, c)
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+def _clear_of_tie_rule(x, s):
+    """Coordinates whose median the tie rule decides with room to spare: no
+    prefix of the sorted weights lies within half the tolerance of the
+    tolerance itself from S/2. The kernels sum a prefix in worker order and
+    the oracle in sorted order, so where a prefix sits at the tolerance's
+    edge their roundings may decide the tie differently."""
+    x, s = np.asarray(x, np.float64), np.asarray(s, np.float64)
+    m = x.shape[0]
+    cum = np.cumsum(s[np.argsort(x, axis=0, kind="stable")], axis=0)
+    half = 0.5 * s.sum()
+    tol = 4.0 * m * np.finfo(np.float32).eps * half
+    edge = np.abs(np.abs(cum - half) - tol) <= 0.5 * tol
+    return ~edge.any(axis=0)
+
+
+@pytest.mark.parametrize("m", [3, 4, 5, 17])
+@pytest.mark.parametrize("dtype", DTYPES, ids=str)
+@pytest.mark.parametrize("rank", [2, 3, 4])
+def test_wcwmed_leaf_matches_flat(rank, dtype, m):
+    """The leaf kernel reads (m, C), (m, R, C) and (m, L, R, C) in their own
+    layout and equals the flat kernel on the (m, d) view bit for bit."""
+    shape = _leaf_shape(rank, m, dtype)
+    k1, k2 = jax.random.split(jax.random.fold_in(KEY, 11 * m + rank))
+    x = jax.random.normal(k1, shape).astype(dtype)
+    s = jax.random.uniform(k2, (m,), minval=0.1, maxval=3.0)
+    got = wcwmed.wcwmed_leaf(x, s)
+    assert got.shape == shape[1:] and got.dtype == jnp.float32
+    flat = x.reshape(m, -1)
+    np.testing.assert_array_equal(
+        _bits(got).reshape(-1), _bits(wcwmed.wcwmed_pallas(flat, s)))
+    clear = _clear_of_tie_rule(flat.astype(jnp.float32), s)
+    assert clear.mean() > 0.999
+    np.testing.assert_allclose(np.asarray(got).reshape(-1)[clear],
+                               np.asarray(ref.wcwmed_ref(flat, s))[clear],
+                               atol=1e-5, rtol=1e-5)
+
+
+def _near_tie(shape):
+    """The one-ulp near tie of test_wcwmed_near_tie_within_one_ulp, sorted
+    rows so the first prefix is the near tie."""
+    s = np.array([0.5 + 2.0 ** -24, 0.25, 0.25 - 2.0 ** -24], np.float32)
+    x = np.sort(np.asarray(jax.random.normal(jax.random.fold_in(KEY, 3),
+                                             (3,) + shape)), axis=0)
+    return jnp.asarray(x), jnp.asarray(s)
+
+
+def _exact_tie(shape):
+    """Equal weights of an even m (prefix sums hit S/2 exactly) over values
+    with repeats across workers."""
+    x = jax.random.randint(jax.random.fold_in(KEY, 4), (4,) + shape, -3, 4)
+    return x.astype(jnp.float32) * 0.5, jnp.ones((4,))
+
+
+def _zero_weights(shape):
+    return jax.random.normal(jax.random.fold_in(KEY, 5), (5,) + shape), \
+        jnp.zeros((5,))
+
+
+def _infinite(shape):
+    """A fifth of the values +inf and a fifth -inf, unequal weights: a
+    median, or a tie average, of infinities."""
+    k1, k2, k3 = jax.random.split(jax.random.fold_in(KEY, 6), 3)
+    x = jax.random.normal(k1, (5,) + shape)
+    u = jax.random.uniform(k2, x.shape)
+    x = jnp.where(u < 0.2, jnp.inf, jnp.where(u > 0.8, -jnp.inf, x))
+    return x, jax.random.uniform(k3, (5,), minval=0.1, maxval=3.0)
+
+
+@pytest.mark.parametrize("shape", [(640,), (2, 40, 384)], ids=str)
+@pytest.mark.parametrize("case", [_exact_tie, _near_tie, _zero_weights,
+                                  _infinite],
+                         ids=lambda f: f.__name__[1:])
+def test_wcwmed_leaf_ties(case, shape):
+    x, s = case(shape)
+    got = wcwmed.wcwmed_leaf(x, s)
+    flat = x.reshape(x.shape[0], -1)
+    np.testing.assert_array_equal(
+        _bits(got).reshape(-1), _bits(wcwmed.wcwmed_pallas(flat, s)))
+    np.testing.assert_allclose(np.asarray(got).reshape(-1),
+                               np.asarray(ref.wcwmed_ref(flat, s)), atol=1e-6)
+
+
+def test_wcwmed_leaf_nan_stays_in_its_column():
+    """A NaN has no place in the (x, i) order: ``wmed_tile`` counts neither
+    of a pair with a NaN before the other, the leaf kernel's one compare a
+    pair counts one of them. So in a column that holds a NaN the two may
+    pick differently; every other column is the flat kernel's bit for bit."""
+    x, s = _infinite((4, 256))
+    hit = jax.random.uniform(jax.random.fold_in(KEY, 8), x.shape) < 0.05
+    x = jnp.where(hit, jnp.nan, x)
+    got = _bits(wcwmed.wcwmed_leaf(x, s)).reshape(-1)
+    want = _bits(wcwmed.wcwmed_pallas(x.reshape(5, -1), s))
+    clean = ~np.asarray(hit).reshape(5, -1).any(axis=0)
+    assert 0.5 < clean.mean() < 1.0
+    np.testing.assert_array_equal(got[clean], want[clean])
 
 
 @pytest.mark.parametrize("m,d", SHAPES_MD)

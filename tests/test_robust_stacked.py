@@ -71,3 +71,60 @@ def test_registry():
                  "ctma:cwmed", "ctma:gm", "bucketing:cwmed"):
         out = resolve(spec, lam=0.25)(tree, s)
         assert jax.tree_util.tree_structure(out) == jax.tree_util.tree_structure(tree)
+
+
+def _qwen2_like(m=4, seed=0):
+    """A small tree shaped like qwen2-1.5b-4l's group momenta: the embedding,
+    one scanned layer group (matrices, q/k/v biases, norms) and the final
+    norm, each leaf with the leading group axis m."""
+    d, q, kv, f, v, L = 128, 128, 64, 256, 200, 2
+    dims = {"embed": (v, d), "final_norm": (d,),
+            "groups": [{"ln1": (L, d), "ln2": (L, d),
+                        "mix": {"wq": (L, d, q), "wk": (L, d, kv),
+                                "wv": (L, d, kv), "wo": (L, q, d),
+                                "bq": (L, q), "bk": (L, kv), "bv": (L, kv)},
+                        "mlp": {"wg": (L, d, f), "wu": (L, d, f),
+                                "wd": (L, f, d)}}]}
+    leaves, treedef = jax.tree_util.tree_flatten(
+        dims, is_leaf=lambda t: isinstance(t, tuple))
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    tree = jax.tree_util.tree_unflatten(treedef, [
+        jax.random.normal(k, (m,) + t).astype(jnp.bfloat16)
+        for k, t in zip(keys, leaves)])
+    return tree, jnp.full((m,), 3.0)
+
+
+def _kernel_names(fn, *args):
+    """The name of every Pallas kernel that ``fn`` launches."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(str(getattr(eqn.params["name"], "name",
+                                         eqn.params["name"])))
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else (p,):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+def test_pallas_stacked_ctma_reads_leaves_with_the_leaf_median():
+    """On the pallas backend ω-CTMA over ω-CWMed of a layer-stacked tree
+    gives the jnp backend's result, and every leaf's median is a
+    ``wcwmed_leaf`` launch on the leaf itself, none the flat ``wcwmed``."""
+    from repro.agg import resolve
+    tree, s = _qwen2_like()
+    pallas = resolve("ctma:cwmed", lam=0.25, backend="pallas", interpret=True)
+    jnp_ = resolve("ctma:cwmed", lam=0.25, backend="jnp")
+    np.testing.assert_allclose(np.asarray(_flatten_result(pallas(tree, s))),
+                               np.asarray(_flatten_result(jnp_(tree, s))),
+                               atol=1e-6, rtol=1e-6)
+    names = _kernel_names(pallas, tree, s)
+    assert names.count("wcwmed_leaf") == len(jax.tree_util.tree_leaves(tree))
+    assert "wcwmed" not in names, names

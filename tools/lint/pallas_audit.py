@@ -46,6 +46,9 @@ ASSUMED_DIMS: Dict[str, int] = {
     "c": 64, "h": 8, "p": 64, "n": 64,   # SSD chunk/heads/head_dim/state
     "b": 4, "nc": 4,
     "dp": 8192,      # padded aggregation dim
+    # wcwmed_leaf's blocks, bw workers x br rows x bc lanes in and bo x bc
+    # out, as kernels/wcwmed.py _leaf_block sizes them at m = 64 in f32
+    "bw": 64, "br": 16, "bo": 16, "bc": 896,
 }
 _FALLBACK_DIM = 128
 
@@ -116,6 +119,8 @@ def _eval_dim(node: ast.AST, env: _Env) -> Tuple[int, bool]:
     False once an assumed or fallback binding entered the computation."""
     if isinstance(node, ast.Constant) and isinstance(node.value, int):
         return node.value, True
+    if isinstance(node, ast.Constant) and node.value is None:
+        return 1, True               # a squeezed block dim: one element
     if isinstance(node, ast.Name):
         v = env.lookup(node.id)
         if v is not None:
